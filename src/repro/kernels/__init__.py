@@ -8,9 +8,6 @@
   rmsnorm         — fused RMSNorm
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-dispatch wrapper), ref.py (pure-jnp oracle).  ``_compat.tpu_compiler_params``
-papers over the TPUCompilerParams -> CompilerParams rename across jax
-releases; kernels must use it instead of touching ``pltpu`` directly.
+dispatch wrapper), ref.py (pure-jnp oracle).
 """
-from ._compat import tpu_compiler_params
 from . import flash_attention, ligd_step, moe_gemm, rglru, rmsnorm, wkv6

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -137,7 +136,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((qb,), jnp.float32),
             pltpu.VMEM((qb, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mcsa_flash_attention",

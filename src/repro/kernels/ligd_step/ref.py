@@ -208,21 +208,27 @@ def _joint_ug(fr, f_l, f_e, w, offl):
 # ---------------------------------------------------------------------------
 # Masked chunked projected GD — replaces the lockstep vmapped while_loop.
 # ---------------------------------------------------------------------------
-def _masked_chunked_gd(ug_fn, x, *, lr, eps, max_iters, chunk):
+def _masked_chunked_gd(ug_fn, x, zeros, *, lr, eps, max_iters, chunk):
     """Projected GD with the paper's stopping rules, one lane per user.
 
     Lanes freeze as soon as THEIR stopping rule fires (per-lane iteration
     counters, not the slowest-lane lockstep of a vmapped while_loop); the
     loop early-exits at chunk granularity once every lane is frozen.
+    ``zeros`` seeds the counters: the kernel passes a tile loaded from
+    VMEM, because Mosaic gives a constant loop-carry init a replicated
+    layout it cannot relayout the loop body's result into.
     Returns (x, U(x), iters) with per-lane iteration counts."""
     u, g = ug_fn(x)
-    it = jnp.zeros_like(u)
-    done = jnp.zeros(u.shape, bool)
+    it = zeros
+    done = zeros                          # 0/1 in f32: Mosaic carries no i1
     mi = jnp.float32(max_iters)
+
+    def is_active(it, done):
+        return jnp.logical_and(done == 0.0, it < mi)
 
     def step(_, st):
         x, u, g, it, done = st
-        active = jnp.logical_and(jnp.logical_not(done), it < mi)
+        active = is_active(it, done)
         x_new = tuple(jnp.clip(xi - lr * gi, 0.0, 1.0)
                       for xi, gi in zip(x, g))
         u_new, g_new = ug_fn(x_new)
@@ -233,8 +239,8 @@ def _masked_chunked_gd(ug_fn, x, *, lr, eps, max_iters, chunk):
         x = tuple(jnp.where(active, a, b) for a, b in zip(x_new, x))
         u = jnp.where(active, u_new, u)
         g = tuple(jnp.where(active, a, b) for a, b in zip(g_new, g))
-        done = jnp.where(active, stop, done)
-        it = it + active.astype(it.dtype)
+        done = jnp.where(active, jnp.where(stop, 1.0, 0.0), done)
+        it = it + jnp.where(active, 1.0, 0.0)
         return (x, u, g, it, done)
 
     def chunk_body(st):
@@ -242,17 +248,17 @@ def _masked_chunked_gd(ug_fn, x, *, lr, eps, max_iters, chunk):
 
     def cond(st):
         _, _, _, it, done = st
-        return jnp.any(jnp.logical_and(jnp.logical_not(done), it < mi))
+        return jnp.max(jnp.where(is_active(it, done), 1.0, 0.0)) > 0.0
 
     x, u, _, it, _ = jax.lax.while_loop(cond, chunk_body, (x, u, g, it, done))
     return x, u, it
 
 
-def _layer_solve(fr, x, tab, *, lr, eps, max_iters, chunk, joint):
+def _layer_solve(fr, x, zeros, tab, *, lr, eps, max_iters, chunk, joint):
     """One split point's GD solve; ``tab`` = (f_l, f_e, w, offl)."""
     ug = (_joint_ug if joint else _u1_ug)(fr, tab[0], tab[1], tab[2], tab[3])
-    return _masked_chunked_gd(ug, x, lr=lr, eps=eps, max_iters=max_iters,
-                              chunk=chunk)
+    return _masked_chunked_gd(ug, x, zeros, lr=lr, eps=eps,
+                              max_iters=max_iters, chunk=chunk)
 
 
 def _init_x(fr, init):
@@ -270,15 +276,17 @@ def _sweep_ref(feat, x0, tables, *, lr, eps, max_iters, chunk, warm_start,
     per-layer arrays are (M1, X), best_* are (X,)-shaped."""
     fr = _frows(feat)
     x0 = tuple(x0[i:i + 1, :] for i in range(x0.shape[0]))
+    zeros = jnp.zeros_like(fr["c_dev"])
     tab_arr = jnp.asarray(tables, jnp.float32)          # (M1, 4)
 
     def layer(carry, inp):
         tab, s = inp
         x, u_b, s_b, x_b = carry
         x_start = x if warm_start else _init_x(fr, init)
-        x, u, it = _layer_solve(fr, x_start, (tab[0], tab[1], tab[2], tab[3]),
-                                lr=lr, eps=eps, max_iters=max_iters,
-                                chunk=chunk, joint=joint)
+        x, u, it = _layer_solve(fr, x_start, zeros,
+                                (tab[0], tab[1], tab[2], tab[3]), lr=lr,
+                                eps=eps, max_iters=max_iters, chunk=chunk,
+                                joint=joint)
         better = u < u_b                                 # strict: first min
         u_b = jnp.where(better, u, u_b)
         s_b = jnp.where(better, s, s_b)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _moe_kernel(x_ref, wg_ref, wu_ref, wd_ref, y_ref, acc_scr, *,
                 num_ff_blocks: int, ff: int, ff_block: int):
@@ -78,7 +76,7 @@ def moe_swiglu_tpu(x, wg, wu, wd, *, c_block: int = 128,
         out_specs=pl.BlockSpec((1, cb, d), lambda e, ci, fi: (e, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((cb, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mcsa_moe_swiglu",

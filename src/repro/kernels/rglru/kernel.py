@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _rglru_kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int, seq: int):
     ci = pl.program_id(2)
@@ -75,7 +73,7 @@ def rglru_scan_tpu(a, b, *, chunk: int = 256, channel_block: int = 512,
         out_specs=pl.BlockSpec((1, ck, cb), lambda bi, cbi, ci: (bi, ci, cbi)),
         out_shape=jax.ShapeDtypeStruct((B, S, C), a.dtype),
         scratch_shapes=[pltpu.VMEM((cb,), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mcsa_rglru_scan",

@@ -11,8 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -40,7 +39,7 @@ def rmsnorm_tpu(x, w, *, eps: float = 1e-6, row_block: int = 256,
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rb, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="mcsa_rmsnorm",

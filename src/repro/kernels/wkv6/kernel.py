@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
                  chunk: int, seq: int):
@@ -90,7 +88,7 @@ def wkv6_tpu(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, ck, n), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mcsa_wkv6",

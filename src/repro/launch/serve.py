@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro.api import Session, get_scenario
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _print_serving(serving: dict) -> None:
@@ -95,6 +96,7 @@ def main(argv=None):
                     help="also run the SplitServer mid-stream failover "
                          "path and fold its report into the session")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     sc = get_scenario(args.scenario)
     if sc.serving is None:

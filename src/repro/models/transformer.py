@@ -109,7 +109,23 @@ def _init_stack(cfg: ModelConfig, key, env: MeshEnv, *, cross: bool
 
 
 def init_lm(cfg: ModelConfig, key, env: MeshEnv) -> Tuple[Params, dict]:
-    """Full model params + PartitionSpec tree."""
+    """Full model params + PartitionSpec tree.
+
+    The draws run under ``jax.jit``: eagerly, each weight (a whole stacked
+    layer axis of it) would exist in f32 before its cast, a temporary as
+    large as half the bf16 model at published widths.  Specs are static,
+    so tracing recovers them."""
+    specs: dict = {}
+
+    def build(k):
+        params, s = _init_lm(cfg, k, env)
+        specs.update(s)
+        return params
+
+    return jax.jit(build)(key), specs
+
+
+def _init_lm(cfg: ModelConfig, key, env: MeshEnv) -> Tuple[Params, dict]:
     dt = jnp.dtype(cfg.dtype)
     Vp = padded_vocab(cfg.vocab_size, env.tp)
     k_emb, k_stack, k_enc, k_un = jax.random.split(key, 4)
@@ -608,8 +624,11 @@ def loss_fn(cfg: ModelConfig, params: Params, env: MeshEnv, batch, *,
 def prefill(cfg: ModelConfig, params: Params, env: MeshEnv, batch, *,
             cache_len: int, capacity_factor: float = 1.25,
             unroll: bool = False, triangular: bool = False,
-            kv_quant: bool = False):
-    """Returns (last-position logits (B, Vp) vocab-sharded, caches)."""
+            kv_quant: bool = False, all_positions: bool = False):
+    """Returns (last-position logits (B, Vp) vocab-sharded, caches).
+
+    ``all_positions=True`` returns every position's logits (B, S, Vp)
+    instead: a cache-free teacher-forced pass that checks decode."""
     kv_memory = None
     cross_len = 0
     if cfg.enc_dec:
@@ -626,10 +645,10 @@ def prefill(cfg: ModelConfig, params: Params, env: MeshEnv, batch, *,
         triangular=triangular)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = unembed_logits(env, h[:, -1:], table,
+    logits = unembed_logits(env, h if all_positions else h[:, -1:], table,
                             transpose_table=cfg.tie_embeddings,
-                            valid_vocab=cfg.vocab_size)[:, 0]
-    return logits, new_caches
+                            valid_vocab=cfg.vocab_size)
+    return (logits if all_positions else logits[:, 0]), new_caches
 
 
 def decode_step(cfg: ModelConfig, params: Params, env: MeshEnv, token,

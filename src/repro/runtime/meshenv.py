@@ -18,25 +18,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AxisName = Union[str, Tuple[str, ...], None]
 
-# --------------------------------------------------------------------------
-# shard_map compat: ``jax.shard_map`` only exists on newer jax releases
-# (with a ``check_vma`` kwarg); 0.4.x ships it as
-# ``jax.experimental.shard_map.shard_map`` (with ``check_rep``).
-# --------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-agnostic shard_map (replication checking off by default —
-    every call site in this repo passes explicit out_specs)."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SHARD_MAP_CHECK_KW: check})
+    """``jax.shard_map`` with replication checking off by default — every
+    call site in this repo passes explicit out_specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -5,12 +5,6 @@ import jax
 import numpy as np
 import pytest
 
-try:                             # real hypothesis when the [test] extra is
-    import hypothesis            # installed; deterministic fallback shim
-except ModuleNotFoundError:      # otherwise (no pip access in the image)
-    from repro.testing.hypothesis_fallback import install
-    install()
-
 
 @pytest.fixture(scope="session")
 def rng():

@@ -3,10 +3,6 @@
 delta-updated usage exactly in step with an independent audit sweep,
 residuals must never go negative, and the serving layer's slot sizing
 must be monotone with its pow2 rounding pinned at bucket boundaries.
-
-Runs under real ``hypothesis`` when installed; otherwise
-``tests/conftest.py`` installs ``repro.testing.hypothesis_fallback``
-(same API slice, seeded-random draws) so the properties always run.
 """
 from types import SimpleNamespace
 
