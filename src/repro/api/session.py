@@ -41,12 +41,14 @@ Per-step accounting accumulates as struct-of-arrays and comes back from
 :meth:`Session.metrics` as a :class:`SessionMetrics`; wall-clock spent
 inside the plan / step / drain calls accumulates in
 :attr:`Session.timings` (benchmarks read it instead of wrapping their
-own timers around a private loop).
+own timers around a private loop).  Each phase is also a named span
+(:mod:`repro.telemetry.spans`): ``mcsa.session.step`` and its children
+land in any JAX profiler trace taken while the session runs, and the
+same blocks feed ``timings``.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
@@ -54,6 +56,7 @@ import numpy as np
 from repro.core.events import StepEvents
 from repro.core.faults import clamp_hops
 from repro.core.mobility import HandoffBatch
+from repro.telemetry.spans import span
 
 from .policies import Policy, make_policy
 from .scenario import Scenario
@@ -176,9 +179,19 @@ class Session:
 
     Attributes: ``fleet`` (the live plan table), ``policy``, ``topo``,
     ``profile``, ``devices``, ``mobility``, ``dataplane``,
-    ``steps_taken``, ``total_handoffs``, ``timings`` ({"plan_s",
-    "steps_s", "drain_s", "faults_s", "serve_s"} cumulative wall-clock
-    inside the component calls).
+    ``steps_taken``, ``total_handoffs``, ``timings``: cumulative
+    host-clock seconds inside the component calls, each fed by the span
+    of the same block (:mod:`repro.telemetry.spans`) —
+
+    * ``plan_s``   the static plan (``mcsa.session.plan``)
+    * ``steps_s``  mobility and the handoff replan of every step
+      (``mcsa.mobility.step`` + ``mcsa.planner.on_events``, or
+      ``mcsa.session.on_handoffs`` for policies without ``on_events``)
+    * ``drain_s``  :meth:`drain` (``mcsa.session.drain``)
+    * ``faults_s`` the fault process and its topology updates
+      (``mcsa.session.faults``)
+    * ``serve_s``  the data plane's step and its final drain
+      (``mcsa.session.serve``)
     """
 
     def __init__(self, scenario: Scenario, policy=None, *,
@@ -208,18 +221,16 @@ class Session:
         self.steps_taken = 0
         self.total_handoffs = 0
         self.timings = {"plan_s": 0.0, "steps_s": 0.0, "drain_s": 0.0,
-                        "faults_s": 0.0, "serve_s": 0.0,
-                        "telemetry_s": 0.0}
+                        "faults_s": 0.0, "serve_s": 0.0}
         self._failover_reports: list = []   # via record_failover()
         self._log = {k: [] for k in ("t", "handoffs", "resplits", "relays",
                                      "mean_T", "mean_E", "mean_C",
                                      "availability", "evacuated",
                                      "degraded")}
 
-        t0 = time.perf_counter()
-        aps = self.topo.nearest_ap(self.mobility.positions())
-        self.fleet = self.policy.plan(self.devices, aps)
-        self.timings["plan_s"] = time.perf_counter() - t0
+        with span("session.plan", into=self.timings, key="plan_s"):
+            aps = self.topo.nearest_ap(self.mobility.positions())
+            self.fleet = self.policy.plan(self.devices, aps)
         self.admission = self._admission_summary()
 
         # closed-loop serving data plane (lazy import: the module is
@@ -328,6 +339,10 @@ class Session:
         """One lifecycle step: advance the fault process (when chaos is
         on), advance mobility, replan the handoffs, record accounting.
         Returns a :class:`StepReport`."""
+        with span("session.step", step=self.steps_taken):
+            return self._step()
+
+    def _step(self) -> StepReport:
         sc = self.scenario
         t = self.t
 
@@ -335,26 +350,26 @@ class Session:
         fault_batch = None
         evacuation = None
         if self.fault_model is not None:
-            t0 = time.perf_counter()
-            fault_batch = self.fault_model.step(sc.dt, t)
-            if fault_batch:
-                self.topo.apply_faults(fault_batch)
-                if on_events is None:
-                    # legacy / baseline policies: evacuate BEFORE
-                    # mobility so detection never keys on a dead server
-                    # (event-pipeline policies fold the evacuation into
-                    # the same-step on_events call below instead)
-                    evacuation = self._dispatch_faults(fault_batch)
-                self._track_recovery(fault_batch, t)
-                # fault-driven coverage changes are not user movement:
-                # resync the mobility model's nearest-server tracking so
-                # the next detection doesn't emit handoffs for users who
-                # never moved
-                self.mobility.server = np.asarray(
-                    self.topo.ap_server[self.mobility.ap])
-            else:
-                fault_batch = None
-            self.timings["faults_s"] += time.perf_counter() - t0
+            with span("session.faults", into=self.timings, key="faults_s"):
+                fault_batch = self.fault_model.step(sc.dt, t)
+                if fault_batch:
+                    self.topo.apply_faults(fault_batch)
+                    if on_events is None:
+                        # legacy / baseline policies: evacuate BEFORE
+                        # mobility so detection never keys on a dead
+                        # server (event-pipeline policies fold the
+                        # evacuation into the same-step on_events call
+                        # below instead)
+                        evacuation = self._dispatch_faults(fault_batch)
+                    self._track_recovery(fault_batch, t)
+                    # fault-driven coverage changes are not user
+                    # movement: resync the mobility model's
+                    # nearest-server tracking so the next detection
+                    # doesn't emit handoffs for users who never moved
+                    self.mobility.server = np.asarray(
+                        self.topo.ap_server[self.mobility.ap])
+                else:
+                    fault_batch = None
 
         admitted = None
         if self._admission_aware:
@@ -369,31 +384,35 @@ class Session:
                 self.drain()
             admitted = getattr(self.fleet, "server", None)
 
-        t0 = time.perf_counter()
-        batch = self.mobility.step(sc.dt, t, admitted=admitted) \
-            if admitted is not None else self.mobility.step(sc.dt, t)
+        with span("mobility.step", into=self.timings, key="steps_s",
+                  users=self.mobility.num_users):
+            batch = self.mobility.step(sc.dt, t, admitted=admitted) \
+                if admitted is not None else self.mobility.step(sc.dt, t)
         result = None
         outcome = None
         if on_events is not None and (len(batch) or
                                       fault_batch is not None):
             # the incremental pipeline: this step's handoffs + faults
             # flow through ONE dirty-set solve (last-wins per user)
-            outcome = on_events(
-                StepEvents(t=t, handoffs=batch, faults=fault_batch),
-                self.devices, self.fleet,
-                user_aps=np.asarray(self.mobility.ap))
+            with span("planner.on_events", into=self.timings,
+                      key="steps_s", handoffs=len(batch)):
+                outcome = on_events(
+                    StepEvents(t=t, handoffs=batch, faults=fault_batch),
+                    self.devices, self.fleet,
+                    user_aps=np.asarray(self.mobility.ap))
             result = outcome.result
             evacuation = outcome.evacuation
         elif on_events is None and len(batch):
-            result = self.policy.on_handoffs(batch, self.devices,
-                                             self.fleet)
+            with span("session.on_handoffs", into=self.timings,
+                      key="steps_s", handoffs=len(batch)):
+                result = self.policy.on_handoffs(batch, self.devices,
+                                                 self.fleet)
         # the Policy in-flight contract: a truthy `pending` means a
         # dispatched replan (this step's or an earlier one's — handoff-
         # free steps don't apply it) has not yet reached the fleet table
         in_flight = bool(getattr(self.policy, "pending", False))
         if in_flight:
             result = None             # forcing it would kill the overlap
-        self.timings["steps_s"] += time.perf_counter() - t0
         if outcome is not None and not in_flight \
                 and self.admission is not None \
                 and (len(outcome.dirty) or evacuation is not None):
@@ -406,10 +425,9 @@ class Session:
             # runs AFTER evacuation/replanning: fleet.server already
             # names the evacuation targets, so mid-stream failover lands
             # on the server the planner actually chose
-            t0 = time.perf_counter()
-            serving = self.dataplane.step(sc.dt, t, fleet=self.fleet,
-                                          faults=fault_batch)
-            self.timings["serve_s"] += time.perf_counter() - t0
+            with span("session.serve", into=self.timings, key="serve_s"):
+                serving = self.dataplane.step(sc.dt, t, fleet=self.fleet,
+                                              faults=fault_batch)
 
         if serving is not None and self.estimator is not None:
             # close the loop: harvest this step's samples, fold them
@@ -420,19 +438,18 @@ class Session:
             coll = getattr(self.dataplane, "collector", None)
             iv = sc.serving.feedback_interval
             if coll is not None and (self.steps_taken + 1) % iv == 0:
-                t0 = time.perf_counter()
-                snap = self.estimator.update(coll, t + sc.dt)
-                self.load_snapshot = snap
-                upd = getattr(self.policy, "update_load", None)
-                if upd is not None:
-                    upd(snap)
-                tl = self._telemetry_log
-                tl["t"].append(t + sc.dt)
-                tl["compute_mult_max"].append(
-                    float(snap.compute_mult.max()))
-                tl["backhaul_mult_max"].append(
-                    float(snap.backhaul_mult.max()))
-                self.timings["telemetry_s"] += time.perf_counter() - t0
+                with span("session.feedback"):
+                    snap = self.estimator.update(coll, t + sc.dt)
+                    self.load_snapshot = snap
+                    upd = getattr(self.policy, "update_load", None)
+                    if upd is not None:
+                        upd(snap)
+                    tl = self._telemetry_log
+                    tl["t"].append(t + sc.dt)
+                    tl["compute_mult_max"].append(
+                        float(snap.compute_mult.max()))
+                    tl["backhaul_mult_max"].append(
+                        float(snap.backhaul_mult.max()))
 
         self.steps_taken += 1
         self.total_handoffs += len(batch)
@@ -522,9 +539,8 @@ class Session:
             self.step()
         self.drain()
         if self.dataplane is not None:
-            t0 = time.perf_counter()
-            self.dataplane.drain()   # zero-lost invariant enforced here
-            self.timings["serve_s"] += time.perf_counter() - t0
+            with span("session.serve", into=self.timings, key="serve_s"):
+                self.dataplane.drain()   # zero-lost invariant enforced here
         return self.metrics()
 
     def record_failover(self, report) -> None:
@@ -540,9 +556,8 @@ class Session:
         """Force + scatter any in-flight async replan (no-op for
         synchronous policies).  Returns the applied solver result, if
         any."""
-        t0 = time.perf_counter()
-        res = self.policy.drain(self.fleet)
-        self.timings["drain_s"] += time.perf_counter() - t0
+        with span("session.drain", into=self.timings, key="drain_s"):
+            res = self.policy.drain(self.fleet)
         if res is not None and self.admission is not None:
             # the applied replan moved users between servers: keep the
             # admission summary in sync with the live table

@@ -36,6 +36,8 @@ from typing import Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.telemetry.spans import span
+
 from .faults import clamp_hops
 from .network import Topology
 
@@ -180,44 +182,48 @@ class RandomWaypointMobility:
         against), and coverage changes into the admitted server's own
         coverage are suppressed.  ``None`` keeps the paper's
         nearest-server keying (admitted == nearest under K=1)."""
-        to_wp = self.waypoint - self.xy
-        dist = np.linalg.norm(to_wp, axis=-1)
-        travel = self.speed * dt
-        arrived = travel >= dist
-        safe = np.maximum(dist, 1e-12)[:, None]
-        self.xy = np.where(arrived[:, None], self.waypoint,
-                           self.xy + to_wp / safe * travel[:, None])
-        n_arr = int(arrived.sum())
-        if n_arr:
-            self.waypoint[arrived] = (
-                self.rng.uniform(0, 1, (n_arr, 2)) * self.area)
-            self.speed[arrived] = self.rng.uniform(*self.speed_range, n_arr)
+        with span("mobility.advance"):
+            to_wp = self.waypoint - self.xy
+            dist = np.linalg.norm(to_wp, axis=-1)
+            travel = self.speed * dt
+            arrived = travel >= dist
+            safe = np.maximum(dist, 1e-12)[:, None]
+            self.xy = np.where(arrived[:, None], self.waypoint,
+                               self.xy + to_wp / safe * travel[:, None])
+            n_arr = int(arrived.sum())
+            if n_arr:
+                self.waypoint[arrived] = (
+                    self.rng.uniform(0, 1, (n_arr, 2)) * self.area)
+                self.speed[arrived] = self.rng.uniform(*self.speed_range,
+                                                       n_arr)
 
-        new_ap = np.asarray(self.topo.nearest_ap(self.xy))
-        new_server = np.asarray(self.topo.ap_server[new_ap])
-        moved = new_server != self.server
-        if admitted is None:
-            old = self.server
-        else:
-            old = np.asarray(admitted, np.int64)
-            moved &= new_server != old          # arriving home: no handoff
-        idx = np.nonzero(moved)[0]
-        batch = HandoffBatch(
-            t=t,
-            user=idx,
-            old_server=old[idx].astype(np.int64),
-            new_server=new_server[idx].astype(np.int64),
-            new_ap=new_ap[idx].astype(np.int64),
-            # clamp_hops: under fault injection a hop count can be inf
-            # (dead server / cut backhaul) — keep it a finite,
-            # astronomically expensive path instead of an int64 wrap
-            hops_new=clamp_hops(
-                self.topo.hops[new_ap[idx], new_server[idx]]
-            ).astype(np.int64),
-            hops_back=clamp_hops(
-                self.topo.hops[new_ap[idx], old[idx]]).astype(np.int64))
-        self.ap = new_ap
-        self.server = new_server                # nearest-coverage tracking
+        with span("mobility.nearest_ap"):
+            new_ap = np.asarray(self.topo.nearest_ap(self.xy))
+            new_server = np.asarray(self.topo.ap_server[new_ap])
+        with span("mobility.detect"):
+            moved = new_server != self.server
+            if admitted is None:
+                old = self.server
+            else:
+                old = np.asarray(admitted, np.int64)
+                moved &= new_server != old      # arriving home: no handoff
+            idx = np.nonzero(moved)[0]
+            batch = HandoffBatch(
+                t=t,
+                user=idx,
+                old_server=old[idx].astype(np.int64),
+                new_server=new_server[idx].astype(np.int64),
+                new_ap=new_ap[idx].astype(np.int64),
+                # clamp_hops: under fault injection a hop count can be
+                # inf (dead server / cut backhaul) — keep it a finite,
+                # astronomically expensive path instead of an int64 wrap
+                hops_new=clamp_hops(
+                    self.topo.hops[new_ap[idx], new_server[idx]]
+                ).astype(np.int64),
+                hops_back=clamp_hops(
+                    self.topo.hops[new_ap[idx], old[idx]]).astype(np.int64))
+            self.ap = new_ap
+            self.server = new_server            # nearest-coverage tracking
         return batch
 
 

@@ -81,6 +81,8 @@ from jax.sharding import PartitionSpec as P
 
 from types import SimpleNamespace
 
+from repro.telemetry.spans import span
+
 from .admission import AdmissionReport, admit_waterfill
 from .baselines import run_baseline_batch
 from .costs import (Devices, LayerProfile, apply_congestion,
@@ -576,16 +578,20 @@ class MCSAPlanner:
         pre = None
         if events.faults is not None:
             sync = True               # evacuations must land this step
-            pre = self._fault_preamble(events.faults, devices, fleet,
-                                       user_aps)
+            with span("planner.fault_preamble"):
+                pre = self._fault_preamble(events.faults, devices, fleet,
+                                           user_aps)
         else:
             # bring the table within the async horizon before freezing
             # originals (the default horizon 1 applies everything —
             # exactly the historical one-step-stale behavior)
-            self._apply_inflight(fleet, keep=self.async_horizon - 1)
-        self.dirty.enqueue_handoffs(events.handoffs)
-        dirty = self.dirty.flush()
-        n_hand = dirty.count(HANDOFF)
+            keep = self.async_horizon - 1
+            with span("planner.apply", rows=self._inflight_rows(keep)):
+                self._apply_inflight(fleet, keep=keep)
+        with span("planner.flush"):
+            self.dirty.enqueue_handoffs(events.handoffs)
+            dirty = self.dirty.flush()
+            n_hand = dirty.count(HANDOFF)
 
         if len(dirty) == 0:
             outcome = EventOutcome(t=t, result=None, dirty=dirty,
@@ -596,8 +602,9 @@ class MCSAPlanner:
             sol = self._solve_dirty(dirty, devices, fleet,
                                     reduce=not use_admission)
             if use_admission:
-                result, relays, stays, admission = self._admit_dirty(
-                    dirty, devices, fleet, sol)
+                with span("planner.admit"):
+                    result, relays, stays, admission = self._admit_dirty(
+                        dirty, devices, fleet, sol)
                 outcome = EventOutcome(
                     t=t, result=result, dirty=dirty, relays=relays,
                     resplits=n_hand - relays, stays=stays)
@@ -610,8 +617,10 @@ class MCSAPlanner:
                                    batch=dirty, attempts=_attempts)
                 self._inflight.append(p)
                 if sync:
-                    self._apply_inflight(fleet, keep=0)
-                    relays = int(np.asarray(p.res.R, bool).sum()) + p.stayed
+                    with span("planner.scatter"):
+                        self._apply_inflight(fleet, keep=0)
+                        relays = int(np.asarray(p.res.R, bool).sum()) \
+                            + p.stayed
                     outcome = EventOutcome(
                         t=t, result=p.res, dirty=dirty, relays=relays,
                         resplits=n_hand - relays, stays=p.stayed)
@@ -757,113 +766,113 @@ class MCSAPlanner:
         n = len(dirty)
         users = dirty.user
         K = min(self.candidates_k, self.topo.num_servers)
-        faulted = self.topo.faulted
-        up = self.topo.server_available() if faulted else None
-        evacish = dirty.kind != HANDOFF
-
-        cand = None
-        cand_invalid = None
-        if K > 1:
-            cand = self.topo.candidates(K)[dirty.new_ap]         # (n, K)
-            hops_new = self.topo.hops[dirty.new_ap[:, None], cand]
-            if faulted:
-                # down/unreachable candidates stay in the solve (static
-                # shapes) but are priced out of the selection below
-                cand_invalid = ~up[cand] | ~np.isfinite(
-                    np.asarray(hops_new, np.float64))
-                hops_new = clamp_hops(hops_new)
-            if evacish.any() and (self._hold > 0).any():
-                # recovery hysteresis: evacuees avoid just-recovered
-                # servers unless one is their only surviving candidate
-                held = self._hold > 0
-                base = cand_invalid if cand_invalid is not None \
-                    else np.zeros(cand.shape, bool)
-                strict = base | held[cand]
-                use_strict = evacish & (~strict).any(axis=1)
-                if use_strict.any():
-                    cand_invalid = np.where(use_strict[:, None],
-                                            strict, base)
-            rows = np.repeat(np.arange(n), K)
-            new_server_rows = cand.reshape(-1)
-            hops_new_rows = hops_new.reshape(-1)
-        else:
-            rows = np.arange(n)
-            new_server_rows = dirty.new_server
-            hops_new_rows = dirty.hops_new
-            if faulted:
-                # the nearest-coverage target may be down (ap_server
-                # falls back to the pre-fault map where nothing is
-                # reachable): retarget those events to the nearest up
-                # server so a handoff can never land on a dead one
-                tgt = np.asarray(new_server_rows, np.int64).copy()
-                dead = ~up[tgt]
-                if dead.any() and up.any():
-                    tgt[dead] = self._nearest_up(dirty.new_ap[dead], up)
-                    new_server_rows = tgt
-                hops_new_rows = clamp_hops(
-                    self.topo.hops[dirty.new_ap, new_server_rows])
-
-        dev_b = gather_devices(devices, users[rows])
-        dev_b["hops"] = jnp.asarray(hops_new_rows, jnp.float32)
-        dev_b["t_ag"] = jnp.full((n * K,), self.t_ag_estimate, jnp.float32)
-        edges_new = self._edges_for(new_server_rows)
-
-        # Frozen original strategies, gathered straight from fleet arrays
-        # (the batched equivalent of mligd.orig_strategy_dict).
-        f_l_np, f_e_np, w_np = self.profile.prefix_tables()
-        s = fleet.split[users][rows]
-        # device-only plans carry r = 0: their rent must price the true
-        # r (zero — nothing rented), but U₂'s f_e_o/(λ(r_o)·c_min) term
-        # would hit 0/0 (f_e = 0 at s = M), so λ sees a unit stand-in
-        # that the zero f_e multiplies away
-        r_raw = fleet.r[users][rows]
-        orig_r_true = jnp.asarray(r_raw, jnp.float32)
-        orig_r = jnp.asarray(np.where(r_raw > 0, r_raw, 1.0), jnp.float32)
-        orig_B = jnp.asarray(fleet.B[users][rows], jnp.float32)
-        orig_servers = fleet.server[users]
-        edges_orig = self._edges_for(orig_servers[rows])
-        origs = {
-            "split": jnp.asarray(s, jnp.int32),
-            "f_l": jnp.asarray(f_l_np[s], jnp.float32),
-            "f_e": jnp.asarray(f_e_np[s], jnp.float32),
-            "w": jnp.asarray(w_np[s], jnp.float32),
-            "r": orig_r,
-            "B": orig_B,
-            "rent": rent_cost(edges_orig, orig_r_true, orig_B),
-        }
-        hops_back_np = dirty.hops_back[rows]
-        if faulted:
-            # a relay-back to a dead original server must price as
-            # unreachable, never as a wrapped/NaN path (EVACUATE/DRAIN
-            # rows arrive pre-clamped at HOP_UNREACHABLE)
-            hops_back_np = clamp_hops(hops_back_np)
-        hops_back = jnp.asarray(hops_back_np, jnp.float32)
-
         pad = _pow2_bucket(n * K) - n * K
-        res = solve_mligd_batch_jit(
-            self.profile,
-            _pad_axis0(dev_b, pad), _pad_axis0(edges_new, pad),
-            _pad_axis0(origs, pad), _pad_axis0(hops_back, pad), self.cfg)
-        if pad:
-            res = jax.tree.map(lambda a: a[:n * K], res)
+        with span("planner.solve_prep", rows=n * K, bucket=n * K + pad):
+            faulted = self.topo.faulted
+            up = self.topo.server_available() if faulted else None
+            evacish = dirty.kind != HANDOFF
 
-        new_server = None
-        if reduce:
+            cand = None
+            cand_invalid = None
             if K > 1:
-                # argmin-U candidate per event (jnp, so the reduction
-                # rides the async dispatch — nothing is forced here)
-                U_eff = res.U.reshape(n, K)
-                if cand_invalid is not None and cand_invalid.any():
-                    U_eff = U_eff + jnp.where(jnp.asarray(cand_invalid),
-                                              jnp.inf, 0.0)
-                best_k = jnp.argmin(U_eff, axis=1)
-                take = lambda a: a.reshape(n, K, *a.shape[1:])[
-                    jnp.arange(n), best_k]
-                res = jax.tree.map(take, res)
-                new_server = jnp.take_along_axis(
-                    jnp.asarray(cand), best_k[:, None], axis=1)[:, 0]
+                cand = self.topo.candidates(K)[dirty.new_ap]         # (n, K)
+                hops_new = self.topo.hops[dirty.new_ap[:, None], cand]
+                if faulted:
+                    # down/unreachable candidates stay in the solve (static
+                    # shapes) but are priced out of the selection below
+                    cand_invalid = ~up[cand] | ~np.isfinite(
+                        np.asarray(hops_new, np.float64))
+                    hops_new = clamp_hops(hops_new)
+                if evacish.any() and (self._hold > 0).any():
+                    # recovery hysteresis: evacuees avoid just-recovered
+                    # servers unless one is their only surviving candidate
+                    held = self._hold > 0
+                    base = cand_invalid if cand_invalid is not None \
+                        else np.zeros(cand.shape, bool)
+                    strict = base | held[cand]
+                    use_strict = evacish & (~strict).any(axis=1)
+                    if use_strict.any():
+                        cand_invalid = np.where(use_strict[:, None],
+                                                strict, base)
+                rows = np.repeat(np.arange(n), K)
+                new_server_rows = cand.reshape(-1)
+                hops_new_rows = hops_new.reshape(-1)
             else:
-                new_server = np.asarray(new_server_rows, np.int64)
+                rows = np.arange(n)
+                new_server_rows = dirty.new_server
+                hops_new_rows = dirty.hops_new
+                if faulted:
+                    # the nearest-coverage target may be down (ap_server
+                    # falls back to the pre-fault map where nothing is
+                    # reachable): retarget those events to the nearest up
+                    # server so a handoff can never land on a dead one
+                    tgt = np.asarray(new_server_rows, np.int64).copy()
+                    dead = ~up[tgt]
+                    if dead.any() and up.any():
+                        tgt[dead] = self._nearest_up(dirty.new_ap[dead], up)
+                        new_server_rows = tgt
+                    hops_new_rows = clamp_hops(
+                        self.topo.hops[dirty.new_ap, new_server_rows])
+
+            dev_b = gather_devices(devices, users[rows])
+            dev_b["hops"] = jnp.asarray(hops_new_rows, jnp.float32)
+            dev_b["t_ag"] = jnp.full((n * K,), self.t_ag_estimate, jnp.float32)
+            edges_new = self._edges_for(new_server_rows)
+
+            # Frozen original strategies, gathered straight from fleet arrays
+            # (the batched equivalent of mligd.orig_strategy_dict).
+            f_l_np, f_e_np, w_np = self.profile.prefix_tables()
+            s = fleet.split[users][rows]
+            # device-only plans carry r = 0: their rent must price the true
+            # r (zero — nothing rented), but U₂'s f_e_o/(λ(r_o)·c_min) term
+            # would hit 0/0 (f_e = 0 at s = M), so λ sees a unit stand-in
+            # that the zero f_e multiplies away
+            r_raw = fleet.r[users][rows]
+            orig_r_true = jnp.asarray(r_raw, jnp.float32)
+            orig_r = jnp.asarray(np.where(r_raw > 0, r_raw, 1.0), jnp.float32)
+            orig_B = jnp.asarray(fleet.B[users][rows], jnp.float32)
+            orig_servers = fleet.server[users]
+            edges_orig = self._edges_for(orig_servers[rows])
+            origs = {
+                "split": jnp.asarray(s, jnp.int32),
+                "f_l": jnp.asarray(f_l_np[s], jnp.float32),
+                "f_e": jnp.asarray(f_e_np[s], jnp.float32),
+                "w": jnp.asarray(w_np[s], jnp.float32),
+                "r": orig_r,
+                "B": orig_B,
+                "rent": rent_cost(edges_orig, orig_r_true, orig_B),
+            }
+            hops_back_np = dirty.hops_back[rows]
+            if faulted:
+                # a relay-back to a dead original server must price as
+                # unreachable, never as a wrapped/NaN path (EVACUATE/DRAIN
+                # rows arrive pre-clamped at HOP_UNREACHABLE)
+                hops_back_np = clamp_hops(hops_back_np)
+            hops_back = jnp.asarray(hops_back_np, jnp.float32)
+            args = (_pad_axis0(dev_b, pad), _pad_axis0(edges_new, pad),
+                    _pad_axis0(origs, pad), _pad_axis0(hops_back, pad))
+        with span("planner.dispatch"):
+            res = solve_mligd_batch_jit(self.profile, *args, self.cfg)
+            if pad:
+                res = jax.tree.map(lambda a: a[:n * K], res)
+
+            new_server = None
+            if reduce:
+                if K > 1:
+                    # argmin-U candidate per event (jnp, so the reduction
+                    # rides the async dispatch — nothing is forced here)
+                    U_eff = res.U.reshape(n, K)
+                    if cand_invalid is not None and cand_invalid.any():
+                        U_eff = U_eff + jnp.where(jnp.asarray(cand_invalid),
+                                                  jnp.inf, 0.0)
+                    best_k = jnp.argmin(U_eff, axis=1)
+                    take = lambda a: a.reshape(n, K, *a.shape[1:])[
+                        jnp.arange(n), best_k]
+                    res = jax.tree.map(take, res)
+                    new_server = jnp.take_along_axis(
+                        jnp.asarray(cand), best_k[:, None], axis=1)[:, 0]
+                else:
+                    new_server = np.asarray(new_server_rows, np.int64)
 
         return SimpleNamespace(res=res, K=K, cand=cand,
                                cand_invalid=cand_invalid,
@@ -1121,6 +1130,11 @@ class MCSAPlanner:
         serving capacity tracks what admission actually granted."""
         return self.ledger.slot_counts(r_per_slot, min_slots=min_slots,
                                        max_slots=max_slots)
+
+    def _inflight_rows(self, keep: int) -> int:
+        """Rows that ``_apply_inflight(fleet, keep)`` would scatter."""
+        n = max(0, len(self._inflight) - max(0, keep))
+        return sum(len(p.users) for p in self._inflight[:n])
 
     def _apply_inflight(self, fleet: FleetState,
                         keep: int = 0) -> Optional[MLiGDResult]:

@@ -14,11 +14,19 @@ examples/serve_split.py.  The closed-loop data plane
 (:mod:`repro.serving.dataplane`) runs one engine per edge server with
 slot counts derived from admission r-budgets; see docs/ARCHITECTURE.md
 ("Serving data plane").
+
+Every step is a named span (:mod:`repro.telemetry.spans`):
+``mcsa.engine.step`` holds ``engine.admit`` (per admission
+``engine.prefill``, ``engine.first_token`` and ``engine.slot_write``, all
+carrying the request's ``rid``), ``engine.decode`` (the dispatch),
+``engine.token_pull`` (the host waiting for the step's tokens) and
+``engine.emit``; docs/ARCHITECTURE.md ("Spans").
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -28,6 +36,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
 from repro.runtime.meshenv import CPU_ENV, MeshEnv
+from repro.telemetry.spans import span
 
 Params = Dict[str, Any]
 
@@ -38,6 +47,7 @@ class Request:
     tokens: np.ndarray              # prompt (S,)
     max_new: int
     out: List[int] = dataclasses.field(default_factory=list)
+    t_submit: float = 0.0           # host clock at submit (perf_counter)
 
     @property
     def done(self) -> bool:
@@ -140,51 +150,60 @@ class InferenceEngine:
         rid = self._next_rid
         self._next_rid += 1
         self._queue.append(Request(rid=rid, tokens=np.asarray(tokens),
-                                   max_new=max_new))
+                                   max_new=max_new,
+                                   t_submit=time.perf_counter()))
         return rid
 
     def admit(self) -> List[int]:
         """Admit queued requests into free slots, FIFO.  Each admission
         prefills the prompt and emits the first token.  Returns the rids
         admitted this call, in admission order."""
-        admitted: List[int] = []
-        free = [i for i in range(self.slots) if not self.state.active[i]]
-        while free and self._queue:
-            slot = free.pop(0)
-            req = self._queue.pop(0)
-            S = len(req.tokens)
-            Sp = _bucket(S)
-            prompt = np.zeros((1, Sp), np.int32)
-            prompt[0, :S] = req.tokens
-            # NOTE: right-pad + prefill at padded length is wasteful but
-            # simple; positions beyond S are causally masked out for the
-            # last-token logits because we re-decode from position S below.
-            logits, caches = self._prefill_fn(self.params,
-                                              jnp.asarray(prompt[:, :S]),
-                                              prompt_len=S)
-            nxt = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
-            req.out.append(nxt)
-            # copy this request's caches into its slot (scan-stacked cache
-            # leaves carry a leading superblock axis — the slot axis is
-            # wherever the pool is `slots`-wide and the request is 1-wide)
-            self.state.caches = jax.tree.map(
-                lambda pool, one: _slot_write(pool, one, slot, self.slots),
-                self.state.caches, caches)
-            lt = self.state.last_token.at[slot, 0].set(nxt)
-            self.state.last_token = lt
-            self.state.pos[slot] = S
-            self.state.active[slot] = True
-            self.requests[req.rid] = req
-            if req.done:
-                # max_new == 1: the prefill token satisfied the request
-                # (the data plane hits this re-prefilling a migrated
-                # stream with one token left) — free the slot at once.
-                self.state.active[slot] = False
-                free.insert(0, slot)
-            else:
-                self.slot_of[req.rid] = slot
-            admitted.append(req.rid)
-        return admitted
+        with span("engine.admit"):
+            admitted: List[int] = []
+            free = [i for i in range(self.slots) if not self.state.active[i]]
+            while free and self._queue:
+                slot = free.pop(0)
+                req = self._queue.pop(0)
+                S = len(req.tokens)
+                Sp = _bucket(S)
+                prompt = np.zeros((1, Sp), np.int32)
+                prompt[0, :S] = req.tokens
+                # NOTE: right-pad + prefill at padded length is wasteful
+                # but simple; positions beyond S are causally masked out for
+                # the last-token logits because we re-decode from position
+                # S below.
+                waited = time.perf_counter() - req.t_submit
+                with span("engine.prefill", rid=req.rid, prompt_len=S,
+                          queued_ms=waited * 1e3):
+                    logits, caches = self._prefill_fn(
+                        self.params, jnp.asarray(prompt[:, :S]), prompt_len=S)
+                with span("engine.first_token", rid=req.rid):
+                    nxt = int(jnp.argmax(logits[0, :self.cfg.vocab_size]))
+                req.out.append(nxt)
+                # copy this request's caches into its slot (scan-stacked
+                # cache leaves carry a leading superblock axis — the slot
+                # axis is wherever the pool is `slots`-wide and the request
+                # is 1-wide)
+                with span("engine.slot_write", rid=req.rid):
+                    self.state.caches = jax.tree.map(
+                        lambda pool, one: _slot_write(pool, one, slot,
+                                                      self.slots),
+                        self.state.caches, caches)
+                    lt = self.state.last_token.at[slot, 0].set(nxt)
+                    self.state.last_token = lt
+                self.state.pos[slot] = S
+                self.state.active[slot] = True
+                self.requests[req.rid] = req
+                if req.done:
+                    # max_new == 1: the prefill token satisfied the request
+                    # (the data plane hits this re-prefilling a migrated
+                    # stream with one token left) — free the slot at once.
+                    self.state.active[slot] = False
+                    free.insert(0, slot)
+                else:
+                    self.slot_of[req.rid] = slot
+                admitted.append(req.rid)
+            return admitted
 
     # Kept for callers/tests predating the public ``admit``.
     _admit = admit
@@ -196,17 +215,18 @@ class InferenceEngine:
         queue).  The request is forgotten entirely — used by the data
         plane for deadline timeouts and mid-stream migration, where the
         produced prefix is re-prefilled elsewhere."""
-        for i, req in enumerate(self._queue):
-            if req.rid == rid:
-                self._queue.pop(i)
-                return list(req.out)
-        slot = self.slot_of.pop(rid, None)
-        if slot is not None:
-            self.state.active[slot] = False
-        req = self.requests.pop(rid, None)
-        if req is None:
-            raise KeyError(f"unknown rid {rid}")
-        return list(req.out)
+        with span("engine.cancel", rid=rid):
+            for i, req in enumerate(self._queue):
+                if req.rid == rid:
+                    self._queue.pop(i)
+                    return list(req.out)
+            slot = self.slot_of.pop(rid, None)
+            if slot is not None:
+                self.state.active[slot] = False
+            req = self.requests.pop(rid, None)
+            if req is None:
+                raise KeyError(f"unknown rid {rid}")
+            return list(req.out)
 
     def pop_result(self, rid: int) -> List[int]:
         """Remove a finished (or cancelled-from-queue) request and return
@@ -263,70 +283,76 @@ class InferenceEngine:
         overflows, because position ``pos`` must still be written), and
         ``RuntimeError`` when no slot is free — callers gate on
         :attr:`free_slots` like they do for :meth:`admit`."""
-        pos = int(pos)
-        tokens = np.asarray(tokens)
-        if max_new < 1:
-            raise ValueError("import_cache needs max_new >= 1 (a "
-                             "finished stream has nothing to migrate)")
-        if pos < 1 or len(tokens) < 1:
-            raise ValueError("import_cache needs a non-empty prefix")
-        if pos + max_new > self.cache_len:
-            raise CacheOverflowError(
-                f"migrated prefix (pos={pos}) + {max_new} decode "
-                f"position(s) exceed cache_len={self.cache_len}")
-        free = [i for i in range(self.slots) if not self.state.active[i]]
-        if not free:
-            raise RuntimeError("import_cache: no free slot")
-        slot = free[0]
+        with span("engine.import_cache"):
+            pos = int(pos)
+            tokens = np.asarray(tokens)
+            if max_new < 1:
+                raise ValueError("import_cache needs max_new >= 1 (a "
+                                 "finished stream has nothing to migrate)")
+            if pos < 1 or len(tokens) < 1:
+                raise ValueError("import_cache needs a non-empty prefix")
+            if pos + max_new > self.cache_len:
+                raise CacheOverflowError(
+                    f"migrated prefix (pos={pos}) + {max_new} decode "
+                    f"position(s) exceed cache_len={self.cache_len}")
+            free = [i for i in range(self.slots) if not self.state.active[i]]
+            if not free:
+                raise RuntimeError("import_cache: no free slot")
+            slot = free[0]
 
-        def put(pool, tmpl, one):
-            s_ax, c_ax = _cache_axes(pool.shape, tmpl.shape, self.slots,
-                                     self.cache_len)
-            if c_ax is not None and one.shape[c_ax] < pool.shape[c_ax]:
-                pads = [(0, 0)] * pool.ndim
-                pads[c_ax] = (0, pool.shape[c_ax] - one.shape[c_ax])
-                one = jnp.pad(one, pads)
-            return _slot_write(pool, one, slot, self.slots)
+            def put(pool, tmpl, one):
+                s_ax, c_ax = _cache_axes(pool.shape, tmpl.shape, self.slots,
+                                         self.cache_len)
+                if c_ax is not None and one.shape[c_ax] < pool.shape[c_ax]:
+                    pads = [(0, 0)] * pool.ndim
+                    pads[c_ax] = (0, pool.shape[c_ax] - one.shape[c_ax])
+                    one = jnp.pad(one, pads)
+                return _slot_write(pool, one, slot, self.slots)
 
-        self.state.caches = jax.tree.map(put, self.state.caches,
-                                         self._tmpl, leaves)
-        rid = self._next_rid
-        self._next_rid += 1
-        req = Request(rid=rid, tokens=tokens, max_new=max_new)
-        self.state.last_token = self.state.last_token.at[slot, 0].set(
-            int(tokens[-1]))
-        self.state.pos[slot] = pos
-        self.state.active[slot] = True
-        self.requests[rid] = req
-        self.slot_of[rid] = slot
-        return rid
+            self.state.caches = jax.tree.map(put, self.state.caches,
+                                             self._tmpl, leaves)
+            rid = self._next_rid
+            self._next_rid += 1
+            req = Request(rid=rid, tokens=tokens, max_new=max_new)
+            self.state.last_token = self.state.last_token.at[slot, 0].set(
+                int(tokens[-1]))
+            self.state.pos[slot] = pos
+            self.state.active[slot] = True
+            self.requests[rid] = req
+            self.slot_of[rid] = slot
+            return rid
 
     # ------------------------------------------------------------------
     def step(self) -> List[Tuple[int, int]]:
         """Admit + one decode for all active slots.
         Returns [(rid, token)] emitted this step."""
-        self.admit()
-        if not self.state.active.any():
-            return []
-        logits, nxt, caches = self._decode_fn(
-            self.params, self.state.last_token,
-            jnp.asarray(self.state.pos, jnp.int32), self.state.caches)
-        self.state.caches = caches
-        self.state.last_token = nxt[:, None]
-        nxt_np = np.asarray(nxt)
-        emitted = []
-        for rid, slot in list(self.slot_of.items()):
-            if not self.state.active[slot]:
-                continue
-            req = self.requests[rid]
-            tok = int(nxt_np[slot])
-            req.out.append(tok)
-            self.state.pos[slot] += 1
-            emitted.append((rid, tok))
-            if req.done:
-                self.state.active[slot] = False
-                del self.slot_of[rid]
-        return emitted
+        with span("engine.step", active=len(self.slot_of),
+                  queued=len(self._queue)):
+            self.admit()
+            if not self.state.active.any():
+                return []
+            with span("engine.decode"):
+                logits, nxt, caches = self._decode_fn(
+                    self.params, self.state.last_token,
+                    jnp.asarray(self.state.pos, jnp.int32), self.state.caches)
+                self.state.caches = caches
+                self.state.last_token = nxt[:, None]
+            with span("engine.token_pull"):
+                nxt_np = np.asarray(nxt)
+            with span("engine.emit"):
+                emitted = []
+                for rid, slot in list(self.slot_of.items()):
+                    if not self.state.active[slot]:
+                        continue
+                    req = self.requests[rid]
+                    tok = int(nxt_np[slot])
+                    req.out.append(tok)
+                    self.state.pos[slot] += 1
+                    emitted.append((rid, tok))
+                    if req.done:
+                        self.state.active[slot] = False
+                        del self.slot_of[rid]
+            return emitted
 
     def run_to_completion(self, max_steps: int = 10_000, *,
                           strict: bool = True):
