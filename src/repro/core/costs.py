@@ -322,6 +322,17 @@ def gather_devices(devs: Devices, idx: np.ndarray) -> dict:
     return stack_devices([devs[int(i)] for i in idx])
 
 
+def gather_devices_np(devs: Devices, idx: np.ndarray
+                      ) -> Dict[str, np.ndarray]:
+    """:func:`gather_devices` on the host: the same float32 rows as numpy
+    arrays, for a caller that pads and transfers the batch itself."""
+    if isinstance(devs, DeviceFleet):
+        return {k: v[idx].astype(np.float32)
+                for k, v in devs.arrays.items()}
+    return {k: np.asarray([getattr(devs[int(i)], k) for i in idx],
+                          np.float32) for k in DEV_FIELDS}
+
+
 def stack_edges(edges) -> dict:
     return {k: jnp.asarray([getattr(e, k) for e in edges], jnp.float32)
             for k in EDGE_FIELDS}

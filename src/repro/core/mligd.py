@@ -224,16 +224,31 @@ def solve_mligd_batch(profile: LayerProfile, devs, edge_new, origs,
 _CACHE: dict = {}
 
 
+def with_rent(origs, edge_orig):
+    """``origs`` with its ``rent`` leaf (Eq. 15) priced at the original
+    server's rows ``edge_orig`` (``rho_min``, ``rho_B``, ``B0``,
+    ``gamma_B``) from the truly rented units ``origs["r_true"]`` — the
+    ``r`` leaf may hold a stand-in (see MCSAPlanner._solve_dirty)."""
+    out = {k: v for k, v in origs.items() if k != "r_true"}
+    out["rent"] = rent_cost(edge_orig, origs["r_true"], origs["B"])
+    return out
+
+
 def solve_mligd_batch_jit(profile: LayerProfile, devs, edge_new, origs,
-                          hops_back, cfg: LiGDConfig = LiGDConfig()
-                          ) -> MLiGDResult:
+                          hops_back, cfg: LiGDConfig = LiGDConfig(),
+                          edge_orig=None) -> MLiGDResult:
     """jit-cached batched solve; edge_new may be shared or per-user.
-    Cache keyed by profile content, not id() (see LayerProfile.fingerprint)."""
+    Cache keyed by profile content, not id() (see LayerProfile.fingerprint).
+
+    With ``edge_orig`` given, ``origs`` carries ``r_true`` in place of
+    ``rent`` and the rent is computed inside the jitted solve
+    (:func:`with_rent`), so a caller that hands over host arrays runs no
+    eager device operation before the dispatch."""
     edge_batched = jnp.ndim(next(iter(edge_new.values()))) > 0
     key = (profile.fingerprint, cfg, edge_batched)
     fn = _CACHE.get(key)
     if fn is None:
-        fn = jax.jit(lambda d, e, o, h: solve_mligd_batch(
-            profile, d, e, o, h, cfg))
+        fn = jax.jit(lambda d, e, o, h, eo: solve_mligd_batch(
+            profile, d, e, o if eo is None else with_rent(o, eo), h, cfg))
         _CACHE[key] = fn
-    return fn(devs, edge_new, origs, hops_back)
+    return fn(devs, edge_new, origs, hops_back, edge_orig)

@@ -23,8 +23,10 @@ See the kernel package docstring for the selection rules.
 Plans live in :class:`FleetState`, a struct-of-arrays table (one (X,)
 array per quantity), so planning X users costs O(fields) Python plus one
 jitted solve — never O(X) interpreter work.  Handoff batches are padded
-to power-of-two sizes before the jitted MLi-GD solve so the jit cache
-holds at most log2(X_max) entries as event counts fluctuate step to step.
+to power-of-two sizes on the host before the jitted MLi-GD solve, and cut
+back on the host where the result is forced, so the jit cache holds at
+most log2(X_max) entries as event counts fluctuate step to step and no
+device program is sized by a step's own event count.
 
 Optionally the static solve shards users across devices with ``shard_map``
 (pass a ``repro.runtime.meshenv.MeshEnv``); each device runs the identical
@@ -86,7 +88,7 @@ from repro.telemetry.spans import span
 from .admission import AdmissionReport, admit_waterfill
 from .baselines import run_baseline_batch
 from .costs import (Devices, LayerProfile, apply_congestion,
-                    gather_devices, rent_cost, stack_devices,
+                    gather_devices, gather_devices_np, stack_devices,
                     stack_edges_np)
 from .events import (DRAIN, EVACUATE, HANDOFF, DirtyBatch, DirtySet,
                      EventOutcome, StepEvents)
@@ -201,12 +203,20 @@ def _pow2_bucket(n: int, floor: int = 8) -> int:
     return max(floor, 1 << (n - 1).bit_length())
 
 
-def _pad_axis0(tree, pad: int):
-    if pad == 0:
-        return tree
-    return jax.tree.map(
-        lambda a: jnp.concatenate(
-            [a, jnp.broadcast_to(a[:1], (pad,) + a.shape[1:])]), tree)
+def _pad_rows(a: np.ndarray, pad: int) -> np.ndarray:
+    """``a`` with its row 0 repeated ``pad`` times at the end (host-side
+    padding to a pow2 bucket: padded rows solve a copy of row 0)."""
+    return np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
+
+
+# Edge columns the frozen strategy's rent (Eq. 15) reads.
+_RENT_FIELDS = ("rho_min", "rho_B", "B0", "gamma_B")
+
+
+def _host_rows(res, n: int):
+    """Force ``res`` and keep its first ``n`` rows, on the host (numpy
+    slices, so no device program is sized by ``n``)."""
+    return jax.tree.map(lambda a: a[:n], jax.device_get(res))
 
 
 @dataclasses.dataclass
@@ -214,10 +224,13 @@ class _PendingReplan:
     """A dispatched-but-unapplied MLi-GD solve (async replanning).
 
     ``res`` leaves are un-forced jax arrays — the solve may still be in
-    flight on the backend; forcing happens when the replan is applied.
-    Up to ``MCSAPlanner.async_horizon`` of these can be outstanding at
-    once; they apply FIFO, so a later dispatch's rows win per user."""
+    flight on the backend, at its padded bucket length; :meth:`force`
+    pulls it to the host and keeps the first ``n`` rows when the replan
+    is applied.  Up to ``MCSAPlanner.async_horizon`` of these can be
+    outstanding at once; they apply FIFO, so a later dispatch's rows win
+    per user."""
     res: MLiGDResult
+    n: int                       # rows of ``res`` that are real (E)
     users: np.ndarray            # (E,) fleet rows the decisions scatter to
     orig_servers: np.ndarray     # (E,) pre-solve servers (relay-back target)
     new_server: object           # (E,) effective new server (jax or numpy)
@@ -225,6 +238,12 @@ class _PendingReplan:
                                  # so a fault can retry stale rows
     attempts: int = 0            # fault-retry count for this dispatch
     stayed: int = 0              # hysteresis holds counted at apply time
+
+    def force(self) -> MLiGDResult:
+        """The result on the host with (E,) leaves (forces the solve;
+        idempotent)."""
+        self.res = _host_rows(self.res, self.n)
+        return self.res
 
 
 class MCSAPlanner:
@@ -309,6 +328,14 @@ class MCSAPlanner:
         servers = np.asarray(servers)
         return {k: jnp.asarray(v[servers], jnp.float32)
                 for k, v in self._edge_table_eff.items()}
+
+    def _edge_rows(self, servers: np.ndarray,
+                   fields: Optional[Sequence[str]] = None) -> dict:
+        """:meth:`_edges_for` on the host: float32 numpy rows of the
+        same view (only ``fields`` when given)."""
+        table = self._edge_table_eff
+        return {k: table[k][servers].astype(np.float32)
+                for k in (fields or table)}
 
     def update_load(self, snapshot) -> None:
         """Consume a :class:`repro.telemetry.LoadSnapshot`: every
@@ -480,7 +507,7 @@ class MCSAPlanner:
         ``idx`` — nothing offloaded: no bandwidth, no rent, no admission
         load (shared by the rejection fallback and fault degradation)."""
         d = {k: np.asarray(v, np.float64)
-             for k, v in gather_devices(devices, idx).items()}
+             for k, v in gather_devices_np(devices, idx).items()}
         f_l_M = float(self.profile.prefix_tables()[0][-1])
         T = f_l_M / d["c_dev"] + t_ag / d["k_rounds"]
         E = d["xi"] * d["c_dev"] ** 2 * d["phi"] * f_l_M
@@ -611,18 +638,18 @@ class MCSAPlanner:
                 if pre is not None:
                     pre.admission = admission
             else:
-                p = _PendingReplan(res=sol.res, users=dirty.user,
+                p = _PendingReplan(res=sol.res, n=sol.n, users=dirty.user,
                                    orig_servers=sol.orig_servers,
                                    new_server=sol.new_server,
                                    batch=dirty, attempts=_attempts)
                 self._inflight.append(p)
                 if sync:
                     with span("planner.scatter"):
-                        self._apply_inflight(fleet, keep=0)
-                        relays = int(np.asarray(p.res.R, bool).sum()) \
+                        res = self._apply_inflight(fleet, keep=0)
+                        relays = int(np.asarray(res.R, bool).sum()) \
                             + p.stayed
                     outcome = EventOutcome(
-                        t=t, result=p.res, dirty=dirty, relays=relays,
+                        t=t, result=res, dirty=dirty, relays=relays,
                         resplits=n_hand - relays, stays=p.stayed)
                 else:
                     outcome = EventOutcome(t=t, result=p.res, dirty=dirty,
@@ -814,53 +841,58 @@ class MCSAPlanner:
                     hops_new_rows = clamp_hops(
                         self.topo.hops[dirty.new_ap, new_server_rows])
 
-            dev_b = gather_devices(devices, users[rows])
-            dev_b["hops"] = jnp.asarray(hops_new_rows, jnp.float32)
-            dev_b["t_ag"] = jnp.full((n * K,), self.t_ag_estimate, jnp.float32)
-            edges_new = self._edges_for(new_server_rows)
+            # every input at bucket length on the host: the index columns
+            # are padded by repeating row 0 and each leaf gathered through
+            # them, so the padded rows are copies of row 0
+            dev_rows = _pad_rows(users[rows], pad)
+            srv_new = _pad_rows(new_server_rows, pad)
+            dev_b = gather_devices_np(devices, dev_rows)
+            dev_b["hops"] = _pad_rows(hops_new_rows, pad).astype(np.float32)
+            dev_b["t_ag"] = np.full(n * K + pad, self.t_ag_estimate,
+                                    np.float32)
+            edges_new = self._edge_rows(srv_new)
 
             # Frozen original strategies, gathered straight from fleet arrays
             # (the batched equivalent of mligd.orig_strategy_dict).
             f_l_np, f_e_np, w_np = self.profile.prefix_tables()
-            s = fleet.split[users][rows]
+            s = fleet.split[dev_rows]
             # device-only plans carry r = 0: their rent must price the true
             # r (zero — nothing rented), but U₂'s f_e_o/(λ(r_o)·c_min) term
             # would hit 0/0 (f_e = 0 at s = M), so λ sees a unit stand-in
-            # that the zero f_e multiplies away
-            r_raw = fleet.r[users][rows]
-            orig_r_true = jnp.asarray(r_raw, jnp.float32)
-            orig_r = jnp.asarray(np.where(r_raw > 0, r_raw, 1.0), jnp.float32)
-            orig_B = jnp.asarray(fleet.B[users][rows], jnp.float32)
+            # that the zero f_e multiplies away; the rent itself is priced
+            # inside the jitted solve (mligd.with_rent)
+            r_raw = fleet.r[dev_rows]
             orig_servers = fleet.server[users]
-            edges_orig = self._edges_for(orig_servers[rows])
             origs = {
-                "split": jnp.asarray(s, jnp.int32),
-                "f_l": jnp.asarray(f_l_np[s], jnp.float32),
-                "f_e": jnp.asarray(f_e_np[s], jnp.float32),
-                "w": jnp.asarray(w_np[s], jnp.float32),
-                "r": orig_r,
-                "B": orig_B,
-                "rent": rent_cost(edges_orig, orig_r_true, orig_B),
+                "split": s.astype(np.int32),
+                "f_l": f_l_np[s].astype(np.float32),
+                "f_e": f_e_np[s].astype(np.float32),
+                "w": w_np[s].astype(np.float32),
+                "r": np.where(r_raw > 0, r_raw, 1.0).astype(np.float32),
+                "B": fleet.B[dev_rows].astype(np.float32),
+                "r_true": r_raw.astype(np.float32),
             }
+            edges_orig = self._edge_rows(fleet.server[dev_rows], _RENT_FIELDS)
             hops_back_np = dirty.hops_back[rows]
             if faulted:
                 # a relay-back to a dead original server must price as
                 # unreachable, never as a wrapped/NaN path (EVACUATE/DRAIN
                 # rows arrive pre-clamped at HOP_UNREACHABLE)
                 hops_back_np = clamp_hops(hops_back_np)
-            hops_back = jnp.asarray(hops_back_np, jnp.float32)
-            args = (_pad_axis0(dev_b, pad), _pad_axis0(edges_new, pad),
-                    _pad_axis0(origs, pad), _pad_axis0(hops_back, pad))
+            hops_back = _pad_rows(hops_back_np, pad).astype(np.float32)
+            # one transfer of the whole batch
+            args = jax.device_put((dev_b, edges_new, origs, hops_back,
+                                   edges_orig))
         with span("planner.dispatch"):
-            res = solve_mligd_batch_jit(self.profile, *args, self.cfg)
-            if pad:
-                res = jax.tree.map(lambda a: a[:n * K], res)
+            res = solve_mligd_batch_jit(self.profile, *args[:4], self.cfg,
+                                        edge_orig=args[4])
 
             new_server = None
             if reduce:
                 if K > 1:
                     # argmin-U candidate per event (jnp, so the reduction
                     # rides the async dispatch — nothing is forced here)
+                    res = jax.tree.map(lambda a: a[:n * K], res)
                     U_eff = res.U.reshape(n, K)
                     if cand_invalid is not None and cand_invalid.any():
                         U_eff = U_eff + jnp.where(jnp.asarray(cand_invalid),
@@ -874,7 +906,10 @@ class MCSAPlanner:
                 else:
                     new_server = np.asarray(new_server_rows, np.int64)
 
-        return SimpleNamespace(res=res, K=K, cand=cand,
+        # ``res`` stays bucket-long unless the K>1 reduction cut it; ``n``
+        # is how many of its rows are real
+        return SimpleNamespace(res=res, K=K, n=n if reduce else n * K,
+                               cand=cand,
                                cand_invalid=cand_invalid,
                                new_server_rows=new_server_rows,
                                new_server=new_server,
@@ -925,7 +960,7 @@ class MCSAPlanner:
         users = dirty.user
         up = topo.server_available()
         t_ag = self.t_ag_estimate
-        res_np = jax.tree.map(np.asarray, sol.res)    # forces the solve
+        res_np = _host_rows(sol.res, sol.n)           # forces the solve
 
         if sol.cand is not None:
             cand = sol.cand
@@ -1063,7 +1098,11 @@ class MCSAPlanner:
         """One padded, jitted MLi-GD solve over ALL of this step's handoff
         events — a thin consumer of :meth:`on_events` (HANDOFF rows
         only).  Returns the (unpadded) batched MLiGDResult with (E,)
-        leaves, or None when there are no events.
+        leaves, or None when there are no events; a replan left in
+        flight (``sync=False``) returns its un-forced result at the
+        padded bucket length instead, whose first E rows are the events
+        (cutting it on the device would build a program per event
+        count).
 
         Arguments
         ---------
@@ -1148,7 +1187,7 @@ class MCSAPlanner:
 
     def _apply_one(self, p: _PendingReplan,
                    fleet: FleetState) -> MLiGDResult:
-        res, users = p.res, p.users
+        res, users = p.force(), p.users
         take_back = np.asarray(res.R, bool)
         server = np.where(take_back, p.orig_servers,
                           np.asarray(p.new_server))
@@ -1178,9 +1217,8 @@ class MCSAPlanner:
             return res
         idx = np.nonzero(scatter)[0]
         if len(idx):
-            res_np = jax.tree.map(np.asarray, res)
             fleet.scatter(users[idx], server[idx],
-                          jax.tree.map(lambda a: a[idx], res_np))
+                          jax.tree.map(lambda a: a[idx], res))
         return res
 
     # ------------------------------------------------------------------
@@ -1258,14 +1296,14 @@ class MCSAPlanner:
         entries, self._inflight = self._inflight, []
         retried = 0
         for p in entries:
-            final = np.where(np.asarray(p.res.R, bool), p.orig_servers,
+            res_np = p.force()
+            final = np.where(np.asarray(res_np.R, bool), p.orig_servers,
                              np.asarray(p.new_server))
             final = np.asarray(final, np.int64)
             stale = ~up[final]
             if not stale.any():
                 self._inflight.append(p)  # applies at the next call/drain
                 continue
-            res_np = jax.tree.map(np.asarray, p.res)
             good = np.nonzero(~stale)[0]
             if len(good):
                 fleet.scatter(p.users[good], final[good],

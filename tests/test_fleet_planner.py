@@ -13,9 +13,10 @@ import pytest
 from repro.configs.chain_cnns import nin, vgg16
 from repro.core import ligd as ligd_mod
 from repro.core import mligd as mligd_mod
+from repro.core import planner as planner_mod
 from repro.core.costs import (DeviceFleet, DeviceParams, EdgeParams,
                               LayerProfile, dev_dict, edge_dict,
-                              stack_devices, stack_edges)
+                              gather_devices, stack_devices, stack_edges)
 from repro.core.ligd import LiGDConfig, LiGDResult, solve_ligd_batch_jit
 from repro.core.mligd import orig_strategy_dict, solve_mligd_batch_jit
 from repro.core.mobility import HandoffBatch, RandomWaypointMobility
@@ -26,7 +27,7 @@ from repro.core.profile import profile_of
 CFG = LiGDConfig(max_iters=150)
 
 
-def _hetero_topo():
+def _hetero_topo(**kw):
     """Fixed topology with one strong/cheap and one weak/expensive server
     so crafted handoffs exercise BOTH MLi-GD branches."""
     edges = [
@@ -34,7 +35,7 @@ def _hetero_topo():
         EdgeParams(c_min=2e9, rho_min=5e-3, r_max=4.0),      # 1: weak
         EdgeParams(c_min=500e9, rho_min=1e-5, r_max=64.0),   # 2: strong
     ]
-    return build_topology(16, 3, seed=0, edge_params=edges)
+    return build_topology(16, 3, seed=0, edge_params=edges, **kw)
 
 
 def _seed_reference_on_handoffs(planner, batch, devices, fleet_before):
@@ -214,6 +215,169 @@ def test_handoff_batches_bucket_to_pow2_jit_shapes():
         shapes.add(_pow2_bucket(n))
     # eight distinct event counts, ONE padded solve shape
     assert shapes == {8}
+
+
+def _random_handoffs(topo, fleet, rng, n):
+    """``n`` handoffs of distinct users to random servers, with the hop
+    counts the topology gives."""
+    user = rng.choice(len(fleet), n, replace=False)
+    new_server = rng.integers(0, topo.num_servers, n).astype(np.int64)
+    new_ap = topo.server_aps[new_server].astype(np.int64)
+    old = fleet.server[user].astype(np.int64)
+    return HandoffBatch(
+        t=0.0, user=user, old_server=old, new_server=new_server,
+        new_ap=new_ap,
+        hops_new=topo.hops[new_ap, new_server].astype(np.int64),
+        hops_back=topo.hops[new_ap, old].astype(np.int64))
+
+
+def _replan_setup(mode):
+    """(planner, devices, fleet) of a 24-user fleet on the hetero topology:
+    K=1 uncapacitated for ``sync``/``async``, K=3 under compute budgets
+    (the ledger-aware admission path) for ``capacitated_k3``."""
+    k3 = mode == "capacitated_k3"
+    topo = _hetero_topo(r_capacity=60.0) if k3 else _hetero_topo()
+    planner = MCSAPlanner(profile_of(nin()), topo, CFG,
+                          candidates_k=3 if k3 else 1,
+                          async_replanning=mode == "async")
+    devices = DeviceFleet(c_dev=np.linspace(3e9, 8e9, 24))
+    aps = np.arange(24) % topo.num_aps
+    _, _, fleet = planner.plan_static(devices, aps)
+    return planner, devices, fleet
+
+
+@pytest.mark.parametrize("mode,counts", [
+    ("sync", (9, 11, 13, 15, 10, 12, 14, 16)),
+    ("async", (9, 11, 13, 15, 10, 12, 14, 16)),
+    # n·K rows: 18..30, all in the 32 bucket
+    ("capacitated_k3", (6, 7, 8, 9, 10)),
+])
+def test_handoff_counts_in_one_bucket_build_no_programs(mode, counts):
+    """Every input of the replan is padded to its bucket on the host and
+    cut back on the host: once a bucket's solve is built, a new handoff
+    count in the same bucket builds no device program at all (compiled
+    or loaded from the persistent cache), the apply included."""
+    from jax import monitoring
+    planner, devices, fleet = _replan_setup(mode)
+    K = planner.candidates_k
+    assert len({_pow2_bucket(n * K) for n in counts}) == 1
+    built = []
+
+    def on_duration(event, duration, **_):
+        if event.endswith("backend_compile_duration"):
+            built.append(event)
+
+    def on_event(event, **_):
+        if event.endswith("compilation_cache/cache_hits"):
+            built.append(event)
+
+    rng = np.random.default_rng(1)
+    per_count = []
+    jax.clear_caches()      # no shape met by an earlier test is cached
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+    try:
+        for n in counts:
+            before = len(built)
+            res = planner.on_handoffs(_random_handoffs(
+                planner.topo, fleet, rng, n), devices, fleet)
+            if mode != "async":
+                assert np.asarray(res.R).shape == (n,)
+            per_count.append(len(built) - before)
+        before = len(built)
+        planner.drain(fleet)
+        per_count.append(len(built) - before)
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+        monitoring.unregister_event_listener(on_event)
+    assert per_count[1:] == [0] * len(counts), per_count
+
+
+def _direct_rows(planner, devices, fleet, users, new_srv, hops_new,
+                 hops_back):
+    """MLi-GD over exactly these rows, unpadded, with inputs built by the
+    device-side helpers and the rent priced inside the jit."""
+    f_l, f_e, w = planner.profile.prefix_tables()
+    s = fleet.split[users]
+    r = fleet.r[users]
+    devs = gather_devices(devices, users)
+    devs["hops"] = jnp.asarray(hops_new, jnp.float32)
+    devs["t_ag"] = jnp.full(len(users), planner.t_ag_estimate, jnp.float32)
+    origs = {"split": jnp.asarray(s, jnp.int32),
+             "f_l": jnp.asarray(f_l[s], jnp.float32),
+             "f_e": jnp.asarray(f_e[s], jnp.float32),
+             "w": jnp.asarray(w[s], jnp.float32),
+             "r": jnp.asarray(np.where(r > 0, r, 1.0), jnp.float32),
+             "B": jnp.asarray(fleet.B[users], jnp.float32),
+             "r_true": jnp.asarray(r, jnp.float32)}
+    edge_orig = {k: v for k, v in
+                 planner._edges_for(fleet.server[users]).items()
+                 if k in ("rho_min", "rho_B", "B0", "gamma_B")}
+    res = solve_mligd_batch_jit(
+        planner.profile, devs, planner._edges_for(new_srv), origs,
+        jnp.asarray(hops_back, jnp.float32), planner.cfg,
+        edge_orig=edge_orig)
+    return jax.tree.map(np.asarray, res)
+
+
+@pytest.mark.parametrize("mode", ["async", "sync", "capacitated_k3"])
+def test_replan_scatters_the_direct_solve_rows(mode, monkeypatch):
+    """The bucket-padded replan scatters the rows a direct solve over the
+    unpadded rows gives: split, R and server exactly, U/B/r to float32
+    round-off — through the async K=1 apply, the sync scatter and the
+    K=3 ledger-aware admission."""
+    planner, devices, fleet = _replan_setup(mode)
+    before = copy.deepcopy(fleet)
+    batch = _random_handoffs(planner.topo, fleet,
+                             np.random.default_rng(2), 11)
+    n, u = len(batch), batch.user
+    K = planner.candidates_k
+    if K == 1:
+        cand = batch.new_server[:, None]
+        hops_new = batch.hops_new
+    else:
+        cand = planner.topo.candidates(K)[batch.new_ap]
+        hops_new = planner.topo.hops[batch.new_ap[:, None], cand]
+    direct = _direct_rows(planner, devices, before, np.repeat(u, K),
+                          cand.reshape(-1), hops_new.reshape(-1),
+                          np.repeat(batch.hops_back, K))
+
+    reports = []
+    waterfill = planner_mod.admit_waterfill
+
+    def recording(*a, **kw):
+        reports.append(waterfill(*a, **kw))
+        return reports[-1]
+
+    monkeypatch.setattr(planner_mod, "admit_waterfill", recording)
+    res = planner.on_handoffs(batch, devices, fleet)
+    if mode == "async":
+        np.testing.assert_array_equal(fleet.split[u], before.split[u])
+        res = planner.drain(fleet)
+    if K == 1:
+        pick = np.arange(n)
+    else:
+        (report,) = reports
+        assert not report.rejected.any()
+        pick = np.arange(n) * K + report.choice
+    exp = jax.tree.map(lambda a: a[pick], direct)
+    offl = exp.split < planner.profile.num_layers
+    server = np.where(exp.R == 1, before.server[u],
+                      cand[np.arange(n), pick % K])
+    assert 0 < exp.R.sum() < n       # both branches taken
+    for got in (res, fleet):
+        rows = slice(None) if got is res else u
+        np.testing.assert_array_equal(np.asarray(got.split)[rows],
+                                      exp.split)
+        np.testing.assert_array_equal(np.asarray(got.R)[rows], exp.R)
+        np.testing.assert_allclose(np.asarray(got.U)[rows], exp.U,
+                                   rtol=2e-7)
+        for f in ("B", "r"):
+            want = getattr(exp, f) if K == 1 \
+                else np.where(offl, getattr(exp, f), 0.0)
+            np.testing.assert_allclose(np.asarray(getattr(got, f))[rows],
+                                       want, rtol=2e-7)
+    np.testing.assert_array_equal(fleet.server[u], server)
 
 
 def test_plan_static_sharded_matches_default():
