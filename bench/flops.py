@@ -1,10 +1,15 @@
-"""Operations and bytes of the engine's work, from shapes alone.
+"""The chip's peaks, and the operations and bytes of the dense decoder's work.
 
-The parameter arithmetic follows the program's own layer equations
-(GQA attention with separate q/k/v/o projections, a three-matrix SwiGLU
-MLP, RMSNorm weights, untied embedding and unembedding) and is kept here,
-apart from the program, so that the yardstick does not move with it.
-Every function takes the configuration's ``model`` group as a dict.
+``peaks`` and ``least_time_s`` serve every cell.  The rest counts the
+dense decoder from shapes alone: its parameter arithmetic follows the
+program's own layer equations (GQA attention with separate q/k/v/o
+projections, a three-matrix SwiGLU MLP, RMSNorm weights, untied
+embedding and unembedding) and is kept here, apart from the program, so
+that the yardstick does not move with it.  The dense reference
+(``bench/reference/lm.py``) hands ``decode_step_work`` and
+``prefill_flops`` on to the readers; a reference module of another
+architecture supplies its own.  Every function takes the configuration's
+``model`` group as a dict.
 """
 from __future__ import annotations
 

@@ -12,6 +12,13 @@ running at its end, so a seed that reordered them would change the
 tokens a window holds by about a tenth.)  ``--seed`` draws the tokens,
 uniform over the vocabulary, and the weights.  Decoding is greedy.
 
+The configuration decides the architecture.  Its ``model`` group names
+the program's registered model (``model_id``) and the plain reference
+module (``reference``: ``bench/reference/<module>.py``), which supplies
+the forward pass, the weight-draw rule and the operation counts; nothing
+here names a layer or a leaf.  ``program_config`` holds the registered
+model to the group, and applies the cuts the top-level ``reduced`` lists.
+
 The entry is the engine every data-plane pool wraps,
 ``InferenceEngine.submit`` / ``.step``, driven in wall time from the
 client's side.  Set-up makes the weights on the device from the seed (one
@@ -26,47 +33,71 @@ finished requests, the longest among them.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 import statistics
 import time
 
 import numpy as np
 
-from bench.reference import lm as ref
+from bench.logit_gaps import logit_gaps
 
-MODEL_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
-              "head_dim", "d_ff", "vocab_size", "tie_embeddings",
-              "rope_theta", "norm_eps", "dtype")
+# keys of the model group that name things rather than state a field of
+# the program's model
+NAMING = ("kind", "model_id", "reference")
 
 
-def program_config(m: dict):
-    """The program's registered model, held to every width the
-    configuration states."""
-    from repro.configs import get_config
-    cfg = get_config(m["model_id"])
-    diff = {k: (getattr(cfg, k), m[k]) for k in MODEL_KEYS
-            if getattr(cfg, k) != m[k]}
-    if diff or cfg.pattern != ("global",) or cfg.num_experts:
+def program_config(cfg: dict):
+    """The program's registered model (``model_id``), held to the
+    configuration: every key of the ``model`` group but ``NAMING`` equals
+    the registry's value, except a key the top-level ``reduced`` lists,
+    which is applied (the chip's share of the published model: depth,
+    experts held, a vocabulary slice); and every field the registry sets
+    away from its default (``name`` and ``family`` aside) is stated.  So
+    the reference sees every mechanism of the model the program runs."""
+    from repro.configs import ModelConfig, get_config
+    m = cfg["model"]
+    if "reference" not in m:
+        raise ValueError("the configuration's model group names no "
+                         "`reference` module (bench/reference/<module>.py)")
+    if "reduced" not in cfg:
+        raise ValueError("the configuration states no top-level `reduced` "
+                         "list")
+    reg = get_config(m["model_id"])
+    fields = {f.name: f for f in dataclasses.fields(ModelConfig)}
+    stated = {k: tuple(v) if isinstance(v, list) else v
+              for k, v in m.items() if k not in NAMING}
+    cut = set(cfg["reduced"])
+    faults = []
+    if set(stated) - set(fields):
+        faults.append(f"not fields of the program's model: "
+                      f"{sorted(set(stated) - set(fields))}")
+    if cut - set(stated):
+        faults.append(f"in `reduced` but not stated: "
+                      f"{sorted(cut - set(stated))}")
+    departs = {k: (getattr(reg, k), v) for k, v in sorted(stated.items())
+               if k in fields and k not in cut and getattr(reg, k) != v}
+    if departs:
+        faults.append(f"departing from the registry (registry, file) "
+                      f"without being in `reduced`: {departs}")
+    unstated = sorted(
+        k for k, f in fields.items()
+        if k not in ("name", "family") and k not in stated
+        and (f.default is dataclasses.MISSING or getattr(reg, k) != f.default))
+    if unstated:
+        faults.append(f"set by the registry but not stated: {unstated}")
+    if faults:
         raise ValueError(f"the program's {m['model_id']} departs from the "
-                         f"configuration: {diff}")
-    return cfg
+                         f"configuration: " + "; ".join(faults))
+    return dataclasses.replace(reg, **{k: stated[k] for k in cut})
 
 
-def _fan_in(name: str, shape) -> int:
-    if name in ("wq", "wk", "wv", "wg", "wu", "unembed"):
-        return shape[-2] if name in ("wg", "wu", "unembed") else shape[-3]
-    if name == "wo":
-        return shape[-3] * shape[-2]
-    if name == "wd":
-        return shape[-2]
-    return shape[-1]                                     # embed rows
-
-
-def make_weights(mcfg, seed: int):
+def make_weights(mcfg, seed: int, rule: dict):
     """Every weight of the program's parameter tree, drawn on the device
-    from the seed in one jitted call, in the served dtype: matrices
+    from the seed in one jitted call, in the served dtype, by the
+    reference's ``rule`` (leaf name -> fan-in axes, or None): matrices
     normal with variance 1/fan_in, norm weights normal at 0.1 (they enter
-    as 1 + w)."""
+    as 1 + w).  A leaf the rule does not name is an error."""
     import jax
     import jax.numpy as jnp
     from repro.models import transformer as tfm
@@ -74,17 +105,22 @@ def make_weights(mcfg, seed: int):
     shapes = jax.eval_shape(
         lambda k: tfm.init_lm(mcfg, k, CPU_ENV)[0], jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    unnamed = sorted({jax.tree_util.keystr(path) for path, _ in leaves
+                      if path[-1].key not in rule})
+    if unnamed:
+        raise ValueError(f"the reference's weight rule names no leaf of "
+                         f"{unnamed}")
 
     def gen(key):
         keys = jax.random.split(key, len(leaves))
         out = []
         for (path, sd), k in zip(leaves, keys):
-            name = path[-1].key
+            axes = rule[path[-1].key]
             z = jax.random.normal(k, sd.shape, jnp.float32)
-            if name in ("ln1", "ln2", "final_norm"):
+            if axes is None:
                 z = 0.1 * z
             else:
-                z = z / math.sqrt(_fan_in(name, sd.shape))
+                z = z / math.sqrt(math.prod(sd.shape[a] for a in axes))
             out.append(z.astype(sd.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -195,9 +231,11 @@ def run(ctx) -> dict:
     cfg, tr, seed = ctx["config"], ctx["traffic"], ctx["seed"]
     log = ctx["log"]
     m = cfg["model"]
-    mcfg = program_config(m)
+    mcfg = program_config(cfg)
+    ref = ctx["load_reference"](m["reference"])
+    rule = ref.weight_rule(m)
     t0 = time.perf_counter()
-    params = make_weights(mcfg, seed)
+    params = make_weights(mcfg, seed, rule)
     jax.block_until_ready(params)
     t_w = time.perf_counter() - t0
     engine = InferenceEngine(mcfg, params, env=CPU_ENV,
@@ -255,7 +293,7 @@ def run(ctx) -> dict:
     del engine
     t1 = time.perf_counter()
     checks, control, readings = compare(cfg, params, sample, ctx["hooks"],
-                                         int(tr["check_block"]))
+                                         int(tr["check_block"]), ref.forward)
     log(f"check: {len(sample)} requests against the reference in "
         f"{time.perf_counter() - t1:.2f} s")
 
@@ -269,7 +307,7 @@ def run(ctx) -> dict:
         "attempted": seen["n_due"], "failed": seen["failed"],
         "checks": checks, "memory_peak_bytes": peak,
         "facts": {"steps": len(seen["step_s"]), "step_s": seen["step_s"],
-                  "step_info": seen["step_info"]},
+                  "step_info": seen["step_info"], "reference": ref},
     }
     out["readings"] = readings
     if control is not None:
@@ -287,13 +325,13 @@ def gap_numbers(gaps: np.ndarray) -> dict:
             "token_mismatch_share": float(np.mean(gaps > 0))}
 
 
-def compare(cfg, params, sample, hooks, block: int):
-    """How far each served token's logit lies below the reference's best,
-    over the sample (and the same for the control's first choices, when
-    asked), ``block`` requests at a time so that the reference fits
-    beside the weights; returns (the program's checks, the control's
-    checks or None, every number read, the program's and the
-    control's)."""
+def compare(cfg, params, sample, hooks, block: int, forward):
+    """How far each served token's logit lies below the best of the
+    reference's ``forward``, over the sample (and the same for the
+    control's first choices, when asked), ``block`` requests at a time so
+    that the reference fits beside the weights; returns (the program's
+    checks, the control's checks or None, every number read, the
+    program's and the control's)."""
     import jax.numpy as jnp
     m, lim = cfg["model"], cfg["check"]
     L = cfg["engine"]["cache_len"]
@@ -311,8 +349,8 @@ def compare(cfg, params, sample, hooks, block: int):
         n += len(out)
 
     def gaps(int8=False):
-        out = [np.asarray(ref.logit_gaps(
-            m, params, jnp.asarray(toks[i:i + block]),
+        out = [np.asarray(logit_gaps(
+            forward, m, params, jnp.asarray(toks[i:i + block]),
             jnp.asarray(tgt[i:i + block]), jnp.asarray(valid[i:i + block]),
             int8=int8)) for i in range(0, B, block)]
         return np.concatenate(out)[valid]

@@ -1,8 +1,9 @@
 """Model FLOP/s utilization of the whole engine step, in %: the useful
 operations of every ``InferenceEngine.step`` in the traced window (the
 decode of the active slots with attention over their filled positions,
-and the prefill of the prompts admitted) over the summed host-clock time
-of those calls times the chip's peak bf16 FLOP/s."""
+and the prefill of the prompts admitted, as the configuration's reference
+module counts them) over the summed host-clock time of those calls times
+the chip's peak bf16 FLOP/s."""
 from bench import flops
 
 
@@ -10,11 +11,11 @@ def read(facts):
     info, times = facts.get("step_info") or [], facts.get("step_s") or []
     if not info or len(info) != len(times) or not sum(times):
         return None
-    m = facts["config"]["model"]
+    m, ref = facts["config"]["model"], facts["reference"]
     peak = flops.peaks(facts["device"]["kind"])["bf16_flops_per_s"]
     useful = 0.0
     for _active, positions, admitted in info:
-        useful += flops.decode_step_work(
+        useful += ref.decode_step_work(
             m, list(positions) + list(admitted))["flops"]
-        useful += sum(flops.prefill_flops(m, s) for s in admitted)
+        useful += sum(ref.prefill_flops(m, s) for s in admitted)
     return 100.0 * useful / (sum(times) * peak)

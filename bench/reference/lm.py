@@ -1,14 +1,33 @@
-"""Plain reference of the served decoder, in float32, and its int8 control.
+"""Plain reference of the dense decoder the engine serves, in float32, and its int8 control.
 
-A straightforward forward pass over whole sequences, written from the
-configuration's equations (token embedding scaled by sqrt(d_model);
-pre-norm blocks with RMSNorm whose weight enters as ``1 + w``; grouped
-query attention with rotary embeddings on the two halves of each head,
-causal, scaled by head_dim^-1/2; a SwiGLU MLP; a final RMSNorm and an
-untied unembedding), with no cache, no batching of slots and no kernel.
-It imports nothing of the program: it reads the weights the harness made,
-by name, and upcasts one layer at a time inside a scan so that it fits
-beside them.  Matmuls run at ``precision="highest"``.
+A serve configuration names its reference module in ``model.reference``
+(``bench/reference/<module>.py``); this one is ``lm``.  A reference
+module supplies everything of the serve harness that depends on the
+architecture, and the harness takes it from nowhere else:
+
+- ``forward(m, params, tokens, int8=False)``: the logits of every
+  position (``bench/logit_gaps.py`` compares the served tokens by them);
+- ``weight_rule(m)``: for every leaf name of the program's parameter
+  tree, the axes of the leaf's shape whose product is its fan-in (the
+  harness draws it normal with variance 1/fan-in), or None for a norm
+  weight (0.1 x normal, entering as 1 + w); it refuses a ``model`` group
+  that states what the module does not cover;
+- ``decode_step_work(m, positions)`` and ``prefill_flops(m, prompt_len)``:
+  the operations and bytes the per-layer readers count.
+
+This module covers the dense decoder: token embedding scaled by
+sqrt(d_model); pre-norm blocks with RMSNorm whose weight enters as
+``1 + w``; grouped query attention with rotary embeddings on the two
+halves of each head, causal, scaled by head_dim^-1/2; a SwiGLU MLP; a
+final RMSNorm and an untied unembedding; bfloat16 weights.  A ``model``
+group that states any other key, experts or a layer pattern among them,
+is refused.  Its counts are the dense ones of ``bench/flops.py``.
+
+The forward pass runs over whole sequences, with no cache, no batching of
+slots and no kernel.  It imports nothing of the program: it reads the
+weights the harness made, by name, and upcasts one layer at a time inside
+a scan so that it fits beside them.  Matmuls run at
+``precision="highest"``.
 
 The control computes every weight matmul in int8 instead (weights
 quantized per output channel, activations per token, int32 accumulation),
@@ -17,13 +36,51 @@ the norms stay in float32.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from bench.flops import decode_step_work, prefill_flops  # noqa: F401
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# the keys of the model group this reference reads; ``kind``, ``model_id``
+# and ``reference`` name things and are not read
+KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+        "d_ff", "vocab_size", "tie_embeddings", "rope_theta", "norm_eps",
+        "dtype")
+NAMING = ("kind", "model_id", "reference")
+
+# leaf name -> the axes of its shape whose product is its fan-in, or None
+# for a norm weight; shapes as the program stacks them over layers:
+# wq/wk/wv (L, d, heads, hd), wo (L, H, hd, d), wg/wu (L, d, ff),
+# wd (L, ff, d), embed (V, d), unembed (d, V)
+WEIGHTS = {
+    "embed": (-1,),
+    "wq": (-3,), "wk": (-3,), "wv": (-3,), "wo": (-3, -2),
+    "wg": (-2,), "wu": (-2,), "wd": (-2,), "unembed": (-2,),
+    "ln1": None, "ln2": None, "final_norm": None,
+}
+
+
+def weight_rule(m: dict) -> dict:
+    """The weight-draw rule, for a ``model`` group this reference covers;
+    any other is an error that names what it does not cover."""
+    other = sorted(set(m) - set(KEYS) - set(NAMING))
+    if other:
+        raise ValueError(
+            f"the dense reference `lm` does not cover {other} (experts, "
+            f"layer patterns and other mechanisms need a reference module "
+            f"of their own)")
+    missing = [k for k in KEYS if k not in m]
+    if missing:
+        raise ValueError(f"the model group does not state {missing}, which "
+                         f"the dense reference `lm` reads")
+    if m["tie_embeddings"] or m["dtype"] != "bfloat16":
+        raise ValueError("the dense reference `lm` does not cover tied "
+                         "embeddings or weights other than bfloat16")
+    return WEIGHTS
 
 
 def _mm(x, w, int8: bool):
@@ -93,24 +150,3 @@ def forward(m: dict, params: dict, tokens, int8: bool = False):
     h, _ = jax.lax.scan(block, x, stack["scan"][0])
     h = _rms(h, params["final_norm"], eps)
     return _mm(h, params["unembed"], int8)[..., :m["vocab_size"]]
-
-
-@functools.partial(jax.jit, static_argnames=("m_items", "int8"))
-def _gaps(params, tokens, targets, valid, m_items, int8):
-    m = dict(m_items)
-    ref = forward(m, params, tokens)
-    rms = jnp.sqrt(jnp.mean(ref * ref, axis=-1))
-    best = jnp.max(ref, axis=-1)
-    if int8:
-        targets = jnp.argmax(forward(m, params, tokens, int8=True), -1)
-    chosen = jnp.take_along_axis(ref, targets[..., None], -1)[..., 0]
-    return jnp.where(valid, (best - chosen) / rms, 0.0)
-
-
-def logit_gaps(m: dict, params: dict, tokens, targets, valid,
-               int8: bool = False):
-    """Per position, how far the logit of ``targets`` (the served token)
-    lies below the reference's best, in units of that row's logit RMS;
-    with ``int8`` the target is the token the control puts first."""
-    return _gaps(params, tokens, targets, valid,
-                 tuple(sorted(m.items())), int8)

@@ -7,8 +7,13 @@ Everything a cell needs is found by name: the cell in ``BENCHMARK.json``;
 its configuration in the file that entry names; its traffic mix in
 ``bench/traffic/<traffic>.json``, whose ``kind`` names its generator
 ``bench/generators/<kind>.py``; each per-layer metric in
-``bench/metrics/<metric>.py``.  A new cell, configuration, traffic mix or
-metric is added as files and entries alone.
+``bench/metrics/<metric>.py``; a serve configuration's plain reference in
+``bench/reference/<module>.py``, named by its ``model.reference``, which
+supplies the architecture's forward pass, weight-draw rule and operation
+counts (the generator loads it through ``ctx["load_reference"]``, the
+readers find it in ``facts["reference"]``).  A new cell, configuration,
+traffic mix, metric or serving architecture is added as files and entries
+alone.
 
 The generator builds the system under test, warms every shape the cell
 uses (set-up), runs the measured window, then checks what the window
@@ -196,14 +201,16 @@ def memory_peak_bytes() -> int:
 
 
 def run_cell(args, *, require_chip: bool = True, config_override=None,
-             traffic_override=None, hooks=None) -> dict:
+             traffic_override=None, hooks=None, root: str = ROOT) -> dict:
     """Set up, measure and check one cell; returns the result dict (the
-    result line without printing).  ``config_override`` and ``hooks``
-    serve the tests: a smaller configuration, and faults planted under
-    the timed path (tests only)."""
+    result line without printing).  ``config_override``, ``hooks`` and
+    ``root`` serve the tests: a smaller configuration, faults planted
+    under the timed path, and a copy of the benchmark's files to find the
+    cell in (tests only)."""
     control = bool((hooks or {}).get("control"))
-    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = find_cell(bench, args.workload)
+    bench_dir = os.path.join(root, "bench")
+    bench = load_json(os.path.join(root, "BENCHMARK.json"))
+    cell = find_cell(bench, args.workload, root)
     if config_override is not None:
         cell["config"] = config_override(cell["config"])
     if traffic_override is not None:
@@ -225,8 +232,11 @@ def run_cell(args, *, require_chip: bool = True, config_override=None,
         "compiles": counter, "setup_clock": process_age_s,
         "memory_peak_bytes": memory_peak_bytes, "hooks": hooks or {},
         "log": lambda msg: print(msg, file=sys.stderr, flush=True),
+        "load_reference": lambda name: load_module(
+            os.path.join(bench_dir, "reference", name + ".py"),
+            "bench_reference_" + name),
     }
-    out = generator_for(cell["traffic"]).run(ctx)
+    out = generator_for(cell["traffic"], bench_dir).run(ctx)
     device["memory_peak_bytes"] = out["memory_peak_bytes"]
     facts = out["facts"]
     facts.update(device=device, config=cell["config"],
@@ -239,7 +249,7 @@ def run_cell(args, *, require_chip: bool = True, config_override=None,
         device["window_s"] = red["window_s"]
         metrics = {}
         for m in cell["per_layer"]:
-            v = read_metric(m["name"], facts)
+            v = read_metric(m["name"], facts, bench_dir)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         shutil.rmtree(trace_dir, ignore_errors=True)

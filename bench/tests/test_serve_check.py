@@ -3,52 +3,28 @@ passes; the control (the reference with int8 weight matmuls, judged in
 the program's place), a token altered where it is produced, a decode
 that returns its cache unchanged, and a decode that leaves half of the
 slots out, fail."""
-import json
-
 import pytest
 
-from bench import run
-from helpers import run_small
+from helpers import run_small, tiny_config, tiny_traffic
 
 CELL = "serve.starcoder2-3b.alpaca"
 
 
 @pytest.fixture
-def tiny(monkeypatch):
-    return tiny_overrides(monkeypatch)
+def tiny():
+    return tiny_overrides()
 
 
-def tiny_overrides(monkeypatch):
-    """The serve cell at a tiny width: overrides for ``run_small``."""
-    from repro.configs import get_config, reduced
-    from bench.generators import open_loop
-    cfg = reduced(get_config("starcoder2-3b"), layers=2, d_model=256,
-                  heads=4, kv_heads=2, d_ff=512, vocab=2048)
-    real = run.generator_for
-
-    def generator_for(traffic, bench_dir=run.BENCH):
-        mod = real(traffic, bench_dir)
-        mod.program_config = lambda m: cfg
-        return mod
-    monkeypatch.setattr(run, "generator_for", generator_for)
-
+def tiny_overrides():
+    """The serve cell at a tiny width (its cuts listed in the
+    configuration's ``reduced``): overrides for ``run_small``."""
     def config(c):
-        c = json.loads(json.dumps(c))
-        for k in open_loop.MODEL_KEYS:
-            c["model"][k] = getattr(cfg, k)
-        c["engine"] = {"slots": 4, "cache_len": 96}
+        c = tiny_config(c)
         # the limit at this width, from its own readings on the CPU over
         # three seeds: sound runs 0.0004-0.0019, the control 0.023-0.034
         c["check"] = {"logit_gap_p99": 0.008}
         return c
-
-    def traffic(t):
-        return dict(t, rate_per_s=20.0, first_token_wait_s=20.0,
-                    prompt_len={"median": 8, "sigma": 0.5, "min": 4,
-                                "max": 12},
-                    output_len={"median": 20, "sigma": 0.8, "min": 4,
-                                "max": 80})
-    return {"config_override": config, "traffic_override": traffic}
+    return {"config_override": config, "traffic_override": tiny_traffic}
 
 
 def test_sound_run_is_correct(tiny):
